@@ -34,6 +34,9 @@ def main() -> None:
                     help="comma-separated suite keys (e.g. fig8,fig10)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     failures = 0
     for key, module, desc in SUITES:

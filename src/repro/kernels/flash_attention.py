@@ -86,7 +86,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = True):
-    """q: (B, S, H, hd); k, v: (B, S, K, hd). Returns (B, S, H, hd)."""
+    """q: (B, S, H, hd); k, v: (B, S, K, hd). Returns (B, S, H, hd).
+
+    The kernel runs head-major, (B, H, S, hd): each block's last two dims
+    are then the (block, hd) tile Mosaic requires, which a squeezed head
+    axis in second-to-last place is not."""
     B, S, H, hd = q.shape
     K = k.shape[2]
     G = H // K
@@ -95,28 +99,30 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     block_k = min(block_k, S)
     assert S % block_q == 0 and S % block_k == 0
     nq, nk = S // block_q, S // block_k
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
 
     grid = (B, H, nq, nk)
     kernel = functools.partial(_kernel, causal=causal, window=window,
                                block_q=block_q, block_k=block_k)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block_q, None, hd),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((None, block_k, None, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((None, block_k, None, hd),
-                         lambda b, h, qi, ki: (b, ki, h // G, 0)),
+            pl.BlockSpec((None, None, block_q, hd),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((None, None, block_k, hd),
+                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, None, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
+        out_specs=pl.BlockSpec((None, None, block_q, hd),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), F32),
             pltpu.VMEM((block_q, 1), F32),
             pltpu.VMEM((block_q, hd), F32),
         ],
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    return out.transpose(0, 2, 1, 3)

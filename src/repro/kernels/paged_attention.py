@@ -9,7 +9,9 @@ granularity (DESIGN.md §2).
 
 Layout:
   q            (B, K, G, hd)   G = H/K grouped queries per kv head
-  k/v_pages    (P, T, K, hd)   the pool's KV slab, block size T tokens
+  k/v_pages    (P, K, T, hd)   the pool's KV slab, head-major, block size T
+                               tokens (the page's last two dims are the
+                               (T, hd) tile Mosaic DMAs per grid step)
   block_tables (B, N) int32    physical block ids (scalar-prefetched)
   lengths      (B,) int32      live context per sequence (scalar-prefetched)
 
@@ -85,7 +87,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     interpret: bool = True):
     """q: (B, H, hd) -> (B, H, hd). See module docstring for page layout."""
     B, H, hd = q.shape
-    P, T, K, _ = k_pages.shape
+    P, K, T, _ = k_pages.shape
     N = block_tables.shape[1]
     G = H // K
     assert H % K == 0
@@ -97,7 +99,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     def kv_map(b, k, i, tables, lengths):
         # clamp: blocks past length still need a *valid* page id for the DMA
-        return (tables[b, i], 0, k, 0)
+        return (tables[b, i], k, 0, 0)
 
     def o_map(b, k, i, tables, lengths):
         return (b, k, 0, 0)
@@ -107,8 +109,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid=(B, K, N),
         in_specs=[
             pl.BlockSpec((None, None, G, hd), q_map),
-            pl.BlockSpec((None, T, None, hd), kv_map),
-            pl.BlockSpec((None, T, None, hd), kv_map),
+            pl.BlockSpec((None, None, T, hd), kv_map),
+            pl.BlockSpec((None, None, T, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((None, None, G, hd), o_map),
         scratch_shapes=[

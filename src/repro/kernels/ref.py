@@ -11,20 +11,21 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths):
     """E-Attention oracle: decode attention over paged KV.
 
     q:            (B, H, hd)        one query token per sequence
-    k/v_pages:    (P, T, K, hd)     global paged KV slab (block size T)
+    k/v_pages:    (P, K, T, hd)     global paged KV slab, head-major (block
+                                    size T)
     block_tables: (B, N) int32      physical block ids per sequence
     lengths:      (B,) int32        context length (tokens) per sequence
     Returns (B, H, hd).
     """
     B, H, hd = q.shape
-    P, T, K, _ = k_pages.shape
+    P, K, T, _ = k_pages.shape
     N = block_tables.shape[1]
     G = H // K
 
-    k = k_pages[block_tables]  # (B, N, T, K, hd)
+    k = k_pages[block_tables]  # (B, N, K, T, hd)
     v = v_pages[block_tables]
-    k = k.reshape(B, N * T, K, hd)
-    v = v.reshape(B, N * T, K, hd)
+    k = k.transpose(0, 1, 3, 2, 4).reshape(B, N * T, K, hd)
+    v = v.transpose(0, 1, 3, 2, 4).reshape(B, N * T, K, hd)
 
     qq = q.reshape(B, K, G, hd)
     s = jnp.einsum("bkgh,btkh->bkgt", qq, k, preferred_element_type=F32)

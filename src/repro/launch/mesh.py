@@ -8,20 +8,13 @@ axis adds a second data-parallel tier whose gradient reduction crosses DCI.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh_compat(shape, axes, *, devices=None):
-    """`jax.make_mesh` across jax versions: pass `axis_types` only where the
-    installed jax knows the kwarg (AxisType landed after 0.4.x)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
-                             devices=devices)
-    return jax.make_mesh(shape, axes, devices=devices)
+    """`jax.make_mesh` with every axis Auto-typed."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -8,6 +8,12 @@ cluster simulation.
   PYTHONPATH=src python -m repro.launch.serve \
       --models llama3.2-1b,deepseek-7b --smoke --requests 8
 
+``--smoke`` (the default) serves toy widths for CPU runs; ``--no-smoke``
+serves the published widths, and ``--num-layers N`` cuts each model to its
+first N layers (printed as ``reduced``).  ``main(argv)`` returns the
+engines, and on a trace replay the gateway and its metrics sink, so a
+caller such as ``chip_smoke.py`` can check the run.
+
 With ``--trace {poisson,diurnal,burst}`` the launcher replays a synthesized
 serverless workload through the control-plane Gateway instead of the
 round-robin sequence (DESIGN.md §13): arrivals follow the chosen process,
@@ -34,14 +40,17 @@ fault ledger and (fleet) the dropped/redriven counts.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import itertools
 import time
+from typing import Any, NamedTuple
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs import SHAPES, get_config
+from repro.configs import SHAPES, ModelConfig, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
+from repro.serverless.gateway import generate
 from repro.serving.engine import Engine
 
 
@@ -92,10 +101,26 @@ def _export_obs(tracer, args, extra_summary=None):
         print(f"metrics written: {args.metrics_out}")
 
 
-def main():
+class Served(NamedTuple):
+    """What one launcher run leaves behind, for a caller that checks it
+    (`chip_smoke.py`): the engines, and on a trace replay the gateway and
+    its metrics sink (both None for the round-robin sequence)."""
+
+    engines: list
+    gateway: Any = None
+    sink: Any = None
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--models", default="llama3.2-1b,deepseek-7b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="toy widths for CPU runs; --no-smoke serves the "
+                         "published widths")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="cut every model to its first N layers (a depth "
+                         "cut, printed as `reduced`)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-tokens", type=int, default=16)
@@ -116,7 +141,8 @@ def main():
     ap.add_argument("--trace-seed", type=int, default=0)
     ap.add_argument("--n-engines", type=int, default=1,
                     help="with --trace: route across N engines via the "
-                         "FleetGateway's shared affinity score (§14)")
+                         "FleetGateway's shared affinity score (§14); with "
+                         "at least N local devices, engine i owns device i")
     ap.add_argument("--prewarm", action="store_true",
                     help="with --n-engines: promote models ahead of "
                          "predicted re-arrivals (adaptive keep-alive)")
@@ -134,7 +160,7 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="FILE.json",
                     help="write the unified metrics snapshot (summary "
                          "counters + span accounting) as JSON")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.n_engines < 1:
         ap.error("--n-engines must be >= 1")
     if args.n_engines > 1 and args.trace is None:
@@ -142,6 +168,75 @@ def main():
     if args.chaos and args.trace is None:
         ap.error("--chaos requires --trace (fault schedules replay on the "
                  "trace clock)")
+    if args.num_layers is not None and args.num_layers < 1:
+        ap.error("--num-layers must be >= 1")
+    return args
+
+
+def model_configs(args) -> dict[str, ModelConfig]:
+    """The served configurations: toy widths under --smoke, then the
+    --num-layers depth cut."""
+    cfgs = {}
+    for n in args.models.split(","):
+        cfg = get_config(n)
+        if args.smoke:
+            cfg = cfg.smoke()
+        if args.num_layers is not None and args.num_layers < cfg.num_layers:
+            print(f"reduced: {n} num_layers {cfg.num_layers} -> "
+                  f"{args.num_layers}")
+            cfg = dataclasses.replace(
+                cfg, num_layers=args.num_layers,
+                layer_pattern=cfg.layer_pattern[: args.num_layers])
+        cfgs[n] = cfg
+    return cfgs
+
+
+def build_engines(args, cfgs, *, devices=None, injectors=None,
+                  tracer=None) -> list[Engine]:
+    """One engine per entry of `devices` (default: engine i on local device
+    i when there are at least --n-engines of them, else JAX's default
+    device), each with every model of `cfgs` registered."""
+    if devices is None:
+        local = jax.local_devices()
+        n = args.n_engines
+        devices = local[:n] if len(local) >= n else [None] * n
+    host_bytes = (None if args.host_cache_mb is None
+                  else args.host_cache_mb * 1024 * 1024)
+    engines = [Engine(args.pool_mb * 1024 * 1024, host_cache_bytes=host_bytes,
+                      engine_id=f"engine{i}",
+                      faults=injectors[i] if injectors else None,
+                      tracer=tracer, device=dev)
+               for i, dev in enumerate(devices)]
+    for eng in engines:
+        for n, cfg in cfgs.items():
+            eng.register(n, cfg)
+    return engines
+
+
+def make_replay_trace(args):
+    """The synthesized serverless workload over --models (with --trace)."""
+    from repro.core.trace import SimModel
+    from repro.serverless import make_trace
+
+    sim_models = [SimModel(n, 1e6, 1) for n in args.models.split(",")]
+    return make_trace(args.trace, n_requests=args.requests,
+                      models=sim_models, seed=args.trace_seed,
+                      mean_interarrival=args.mean_interarrival)
+
+
+def fleet_gateway(args, engines, tracer=None):
+    """The multi-engine FleetGateway over `engines`, configured from args."""
+    from repro.serverless import FleetGateway
+
+    return FleetGateway(engines, keep_alive=args.keep_alive_policy,
+                        prefetch=args.prefetch, prewarm=args.prewarm,
+                        prompt_len=args.prompt_len,
+                        gen_tokens=args.gen_tokens, tracer=tracer)
+
+
+def main(argv=None) -> Served:
+    args = parse_args(argv)
+    use_compile_cache()
 
     injectors = None
     fault_events = []
@@ -169,41 +264,21 @@ def main():
         tracer = Tracer(flight=FlightRecorder())
 
     names = args.models.split(",")
-    host_bytes = (None if args.host_cache_mb is None
-                  else args.host_cache_mb * 1024 * 1024)
-    engines = [Engine(args.pool_mb * 1024 * 1024, host_cache_bytes=host_bytes,
-                      engine_id=f"engine{i}",
-                      faults=injectors[i] if injectors else None,
-                      tracer=tracer)
-               for i in range(args.n_engines)]
+    cfgs = model_configs(args)
+    engines = build_engines(args, cfgs, injectors=injectors, tracer=tracer)
     engine = engines[0]
-    cfgs = {}
-    for n in names:
-        cfg = get_config(n)
-        if args.smoke:
-            cfg = cfg.smoke()
-        cfgs[n] = cfg
-        for eng in engines:
-            eng.register(n, cfg)
 
     if args.trace is not None:
         # serverless control plane (§13): synthesize the arrival process
         # over the registered models and replay it through the Gateway —
         # keep-alive decisions run on the trace clock, phase durations are
         # measured wall time
-        from repro.core.trace import SimModel
-        from repro.serverless import FleetGateway, Gateway, make_trace
+        from repro.serverless import Gateway
 
-        sim_models = [SimModel(n, 1e6, 1) for n in names]
-        trace = make_trace(args.trace, n_requests=args.requests,
-                           models=sim_models, seed=args.trace_seed,
-                           mean_interarrival=args.mean_interarrival)
+        trace = make_replay_trace(args)
         if args.n_engines > 1:
             # fleet replay (§14): shared-score routing + optional pre-warm
-            gw = FleetGateway(engines, keep_alive=args.keep_alive_policy,
-                              prefetch=args.prefetch, prewarm=args.prewarm,
-                              prompt_len=args.prompt_len,
-                              gen_tokens=args.gen_tokens, tracer=tracer)
+            gw = fleet_gateway(args, engines, tracer)
             sink = gw.run_trace(trace, faults=fault_events)
             for i, (r, d) in enumerate(zip(sink.records, gw.decisions)):
                 print(f"req {i}: {r.model_id:16s} -> {d[2]} "
@@ -251,9 +326,8 @@ def main():
         _export_obs(tracer, args, extra_summary=s)
         for eng in engines:
             eng.close()
-        return
+        return Served(engines, gw, sink)
 
-    import dataclasses
     seq = list(itertools.islice(itertools.cycle(names), args.requests))
     for i, name in enumerate(seq):
         t0 = time.time()
@@ -269,16 +343,7 @@ def main():
         shape = dataclasses.replace(SHAPES["train_4k"], seq_len=args.prompt_len,
                                     global_batch=2, kind="prefill")
         batch = model.make_batch(jax.random.PRNGKey(i), shape)
-        t1 = time.time()
-        logits = inst.prefill(batch)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        prefill_s = time.time() - t1
-        t2 = time.time()
-        toks = []
-        for _ in range(args.gen_tokens):
-            tok = jnp.argmax(inst.decode(tok), -1).astype(jnp.int32)
-            toks.append(int(tok[0]))
-        decode_s = time.time() - t2
+        _, prefill_s, decode_s = generate(inst, batch, args.gen_tokens)
         inst.finish()
         stats = engine.last_load
         pf = (f" prefetched={stats.bytes_prefetched/1e6:.1f}MB"
@@ -289,6 +354,8 @@ def main():
               f"prefill {prefill_s:.2f}s decode {decode_s/args.gen_tokens*1e3:.0f}ms/tok "
               f"pool_free={engine.store.free_bytes()/1e6:.0f}MB{pf}")
     _export_obs(tracer, args)
+    engine.close()
+    return Served(engines)
 
 
 if __name__ == "__main__":

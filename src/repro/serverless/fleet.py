@@ -60,7 +60,7 @@ from repro.core.trace import (Request, SimModel, synthetic_tensor_sizes,
 from repro.models.tensors import ModelSpec, TensorRecord, VariantSpec
 from repro.obs import NULL_TRACER, BoundedLog, trace_request
 from repro.stats import FleetStats, ModeledFaultStats
-from repro.serverless.gateway import (MetricsSink, TTFTRecord,
+from repro.serverless.gateway import (MetricsSink, TTFTRecord, generate,
                                       make_prefill_batch)
 from repro.serverless.lifecycle import LifecycleManager, make_keep_alive
 from repro.serverless.workload import FaultEvent, PressureEvent
@@ -744,8 +744,6 @@ class FleetGateway:
                queue_s: float) -> tuple[TTFTRecord, float]:
         """Real-plane serve on the routed engine: measured phase walls (the
         single-engine Gateway's split), virtual trace clock for queueing."""
-        import jax.numpy as jnp
-
         eng = node.engine
         t0 = _time.perf_counter()
         rep = submit_load(eng, LoadRequest(req.model_id, now=now))
@@ -756,13 +754,7 @@ class FleetGateway:
         inst = eng.start_instance(req.model_id, num_pages=self.num_pages)
         batch = make_prefill_batch(eng, req.model_id, self.prompt_len,
                                    next(self._req_seq))
-        t1 = _time.perf_counter()
-        tok = jnp.argmax(inst.prefill(batch), -1).astype(jnp.int32)
-        prefill_s = _time.perf_counter() - t1
-        t2 = _time.perf_counter()
-        for _ in range(self.gen_tokens):
-            tok = jnp.argmax(inst.decode(tok), -1).astype(jnp.int32)
-        decode_s = _time.perf_counter() - t2
+        tokens, prefill_s, decode_s = generate(inst, batch, self.gen_tokens)
         inst.finish()
         service_s = _time.perf_counter() - t0
         rec = TTFTRecord(
@@ -770,7 +762,7 @@ class FleetGateway:
             init_s=stats.init_seconds, load_s=load_s,
             profile_s=stats.profile_seconds, prefill_s=prefill_s,
             decode_s=decode_s, prefetched=stats.bytes_prefetched > 0,
-            bytes_from_store=stats.bytes_store)
+            bytes_from_store=stats.bytes_store, tokens=tokens)
         # span/cost cross-check: the measured load wall vs the cost plane's
         # tiered price for the same bytes (the only phase both planes state)
         self._last_preds = {"load": rep.load_seconds}

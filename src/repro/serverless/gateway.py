@@ -49,6 +49,8 @@ class TTFTRecord:
     joined: bool = False
     prefetched: bool = False
     bytes_from_store: int = 0
+    # real plane: the first token from prefill, then one per decode step
+    tokens: tuple[int, ...] = ()
 
     @property
     def ttft(self) -> float:
@@ -144,6 +146,31 @@ def make_prefill_batch(engine, model_id: str, prompt_len: int, seed: int):
     return build_model(cfg).make_batch(jax.random.PRNGKey(seed), shape)
 
 
+def generate(inst, batch, gen_tokens: int):
+    """Prefill `batch` on `inst`, then greedy-decode `gen_tokens` more.
+
+    Returns ``(tokens, prefill_s, decode_s)``: row 0's tokens, and the two
+    phase walls, each stopped only once its last token is on the device —
+    dispatch is asynchronous, so a clock stopped at the enqueue would
+    measure the host alone."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    t1 = _time.perf_counter()
+    tok = jnp.argmax(inst.prefill(batch), -1).astype(jnp.int32)
+    tok.block_until_ready()
+    prefill_s = _time.perf_counter() - t1
+    toks = [tok]
+    t2 = _time.perf_counter()
+    for _ in range(gen_tokens):
+        tok = jnp.argmax(inst.decode(tok), -1).astype(jnp.int32)
+        toks.append(tok)
+    tok.block_until_ready()
+    decode_s = _time.perf_counter() - t2
+    tokens = tuple(int(t[0]) for t in np.asarray(jnp.stack(toks)))
+    return tokens, prefill_s, decode_s
+
+
 class Gateway:
     """Trace replay against a live ``Engine`` under a keep-alive policy.
 
@@ -216,8 +243,6 @@ class Gateway:
 
     def run_trace(self, trace, *,
                   pressure: Sequence[PressureEvent] = ()) -> MetricsSink:
-        import jax.numpy as jnp
-
         press = sorted(pressure, key=lambda p: p.time)
         pi = 0
         # next routed DIFFERENT model per position, one backward pass (the
@@ -259,13 +284,8 @@ class Gateway:
                 self.engine.prefetch(next_model[i])
             inst = self.engine.start_instance(model, num_pages=self.num_pages)
             batch = self._prefill_batch(model, i)
-            t1 = _time.perf_counter()
-            tok = jnp.argmax(inst.prefill(batch), -1).astype(jnp.int32)
-            prefill_s = _time.perf_counter() - t1
-            t2 = _time.perf_counter()
-            for _ in range(self.gen_tokens):
-                tok = jnp.argmax(inst.decode(tok), -1).astype(jnp.int32)
-            decode_s = _time.perf_counter() - t2
+            tokens, prefill_s, decode_s = generate(inst, batch,
+                                                   self.gen_tokens)
             inst.finish()
             # measured service wall occupies the virtual server on the
             # trace clock (decode included: the instance holds its slot
@@ -280,7 +300,7 @@ class Gateway:
                 profile_s=stats.profile_seconds,
                 prefill_s=prefill_s, decode_s=decode_s,
                 prefetched=stats.bytes_prefetched > 0,
-                bytes_from_store=stats.bytes_store)
+                bytes_from_store=stats.bytes_store, tokens=tokens)
             self.sink.add(rec)
             if self.tracer.enabled:
                 # span-accounting identity (DESIGN.md §18): parent span is

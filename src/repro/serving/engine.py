@@ -17,7 +17,8 @@ Fast paths (DESIGN.md §10):
     prefill KV lands in the slab as ONE donated jitted scatter, and
     `Engine.decode_many` fuses same-model instances into a single dispatch.
 
-The KV slab is SHARED per KV geometry (layers x block x kv-heads x head-dim):
+The KV slab is SHARED per KV geometry (layers x kv-heads x block x head-dim,
+head-major so each (page, kv-head) tile is one contiguous (T, hd) block):
 every resident instance of that geometry draws pages from the same buffer, so
 sequences of *different models* interleave physical pages exactly as their
 ElasticKV pool offsets interleave in the Unified Memory Pool (DESIGN.md §8).
@@ -186,8 +187,10 @@ class ChunkedTransfer:
                  max_retries: int = 2, timeout_s: Optional[float] = None,
                  faults: Optional[FaultInjector] = None,
                  fault_stats: Optional[FaultStats] = None,
-                 tracer=NULL_TRACER, track: str = "h2d"):
+                 tracer=NULL_TRACER, track: str = "h2d",
+                 device: Optional[jax.Device] = None):
         assert depth >= 1
+        self.device = device  # None: JAX's default device
         self.chunk_bytes = chunk_bytes
         self.depth = depth
         self.max_retries = max_retries
@@ -217,8 +220,8 @@ class ChunkedTransfer:
                 if self.tracer.enabled:
                     with self.tracer.span("h2d.chunk", track=self.track,
                                           cat="h2d"):
-                        return jax.device_put(host_slice)
-                return jax.device_put(host_slice)
+                        return jax.device_put(host_slice, self.device)
+                return jax.device_put(host_slice, self.device)
             except TransferError as e:
                 # count BEFORE the limit check: the final, re-raised failure
                 # is still a visible retry in the ledger
@@ -598,9 +601,11 @@ class SharedKVSlab:
     interleave pages without coordination — the Unified Memory Pool already
     guarantees the offsets are disjoint."""
 
-    def __init__(self, k_pages: jax.Array, v_pages: jax.Array):
-        self.k_pages = k_pages  # (L, P, T, K, hd)
+    def __init__(self, k_pages: jax.Array, v_pages: jax.Array, *,
+                 device: Optional[jax.Device] = None):
+        self.k_pages = k_pages  # (L, P, K, T, hd)
         self.v_pages = v_pages
+        self.device = device  # growth stays on the owning engine's device
         self.page_map: dict[int, int] = {}  # pool offset -> page index
         self.free_pages: list[int] = []
         self._next_fresh = 0
@@ -638,9 +643,10 @@ class SharedKVSlab:
     def grow(self, num_pages: int):
         if num_pages <= self.num_pages:
             return
-        L, _, T, K, hd = self.k_pages.shape
+        L, _, K, T, hd = self.k_pages.shape
         pad = num_pages - self.num_pages
-        zeros = jnp.zeros((L, pad, T, K, hd), self.k_pages.dtype)
+        zeros = jnp.zeros((L, pad, K, T, hd), self.k_pages.dtype,
+                          device=self.device)
         self.k_pages = jnp.concatenate([self.k_pages, zeros], axis=1)
         self.v_pages = jnp.concatenate([self.v_pages, zeros], axis=1)
 
@@ -661,7 +667,7 @@ class KVMigration:
 
     model_id: str
     snap: KVSnapshot  # metadata-only (pages are None placeholders)
-    k_blob: np.ndarray  # (L, nblk, T, K, hd) host-tier copy of the K pages
+    k_blob: np.ndarray  # (L, nblk, K, T, hd) host-tier copy of the K pages
     v_blob: np.ndarray
     replay: list = field(default_factory=list)  # window tokens, in feed order
 
@@ -681,9 +687,13 @@ class Engine:
                  engine_id: str = "engine0",
                  faults: Optional[FaultInjector] = None,
                  transfer_timeout_s: Optional[float] = None,
-                 tracer=None):
+                 tracer=None, device: Optional[jax.Device] = None):
         # stable identity for fleet routing (the DeviceView's device_id)
         self.engine_id = engine_id
+        # the chip this engine owns: weights, KV slab and init_fn output are
+        # placed there (None: JAX's default device), so N engines of a fleet
+        # can each hold one chip of a host
+        self.device = device
         # obs plane (DESIGN.md §18): the engine's spans land on its own
         # track, stamped with `tracer.clock` (perf_counter walls by default)
         self.tracer = tracer if tracer is not None else NULL_TRACER
@@ -730,7 +740,8 @@ class Engine:
                                      timeout_s=self.transfer_timeout_s,
                                      faults=faults,
                                      fault_stats=self.fault_stats,
-                                     tracer=self.tracer, track=self._track)
+                                     tracer=self.tracer, track=self._track,
+                                     device=device)
         self._tensors: dict[str, jax.Array] = {}  # fingerprint -> live buffer
         self._params_cache: dict[str, Any] = {}  # model_id -> assembled tree
         self._slabs: dict[tuple, SharedKVSlab] = {}  # KV geometry -> slab
@@ -943,7 +954,7 @@ class Engine:
                            and r.fingerprint in self.persistent_store]
             if len(host_hits) + len(spilled) < len(to_move):
                 tm = _time.perf_counter()
-                params = reg.init_fn()  # full materialization: once, ever
+                params = self._init_params(reg)  # full materialization: once
                 with self._store_lock:
                     stats.leaves_materialized = self.host_store.put_tree(
                         reg.records, params)
@@ -993,7 +1004,7 @@ class Engine:
                     # (and nothing else) are re-stored
                     stats.tensors_quarantined = len(quarantined)
                     tm = _time.perf_counter()
-                    params = reg.init_fn()
+                    params = self._init_params(reg)
                     with self._store_lock:
                         stats.leaves_materialized += self.host_store.put_tree(
                             reg.records, params)
@@ -1042,6 +1053,13 @@ class Engine:
                 self.tracer.emit("profile", tp, tp + stats.profile_seconds,
                                  track=self._track, cat="engine",
                                  args={"model": reg.model_id})
+
+    def _init_params(self, reg: RegisteredModel):
+        """Run the model's `init_fn` on this engine's device."""
+        if self.device is None:
+            return reg.init_fn()
+        with jax.default_device(self.device):
+            return reg.init_fn()
 
     # -------------------------------------------------------------- prefetch
     def prefetch(self, model_id: str, *, now: float = 0.0) -> PrefetchJob:
@@ -1292,9 +1310,11 @@ class Engine:
         key = (L, T, K, hd, str(cfg.jnp_dtype))
         slab = self._slabs.get(key)
         if slab is None:
-            shape = (L, num_pages, T, K, hd)
-            slab = SharedKVSlab(jnp.zeros(shape, cfg.jnp_dtype),
-                                jnp.zeros(shape, cfg.jnp_dtype))
+            shape = (L, num_pages, K, T, hd)
+            slab = SharedKVSlab(
+                jnp.zeros(shape, cfg.jnp_dtype, device=self.device),
+                jnp.zeros(shape, cfg.jnp_dtype, device=self.device),
+                device=self.device)
             self._slabs[key] = slab
         else:
             slab.grow(num_pages)
@@ -1389,7 +1409,7 @@ class Engine:
         if len(pages) > inst.max_blocks:
             raise ValueError(f"snapshot needs {len(pages)} blocks but the "
                              f"instance caps at {inst.max_blocks}")
-        idx = jnp.asarray(np.asarray(pages, np.int32))
+        idx = jax.device_put(np.asarray(pages, np.int32), self.device)
         inst.slab.k_pages = inst.slab.k_pages.at[:, idx].set(
             moved[f"kvmig:{mig.model_id}:{req}:k"])
         inst.slab.v_pages = inst.slab.v_pages.at[:, idx].set(
@@ -1722,14 +1742,15 @@ class Instance:
 def _scatter_prefill_kv(k_pages, v_pages, kc, vc, page_ids):
     """Scatter a prefill's dense KV into slab pages in ONE donated op.
 
-    kc/vc: (L, B, nblk, T, K, hd); page_ids: (B, nblk) physical pages, with
-    out-of-range ids (== num_pages) marking padding entries of shorter
-    sequences — scatter mode "drop" discards them.
+    kc/vc: (L, B, nblk, T, K, hd), the dense prefill cache cut into blocks;
+    they land in the head-major slab as (K, T, hd) pages.  page_ids: (B,
+    nblk) physical pages, with out-of-range ids (== num_pages) marking
+    padding entries of shorter sequences — scatter mode "drop" discards them.
     """
     L = kc.shape[0]
     flat = page_ids.reshape(-1)
-    kc = kc.reshape(L, flat.shape[0], *kc.shape[3:])
-    vc = vc.reshape(L, flat.shape[0], *vc.shape[3:])
+    kc = kc.reshape(L, flat.shape[0], *kc.shape[3:]).swapaxes(2, 3)
+    vc = vc.reshape(L, flat.shape[0], *vc.shape[3:]).swapaxes(2, 3)
     k_pages = k_pages.at[:, flat].set(kc, mode="drop")
     v_pages = v_pages.at[:, flat].set(vc, mode="drop")
     return k_pages, v_pages
@@ -1741,7 +1762,7 @@ def _paged_decode_step(params, cfg: ModelConfig, token, tables, lengths,
                        k_pages, v_pages, *, attn: str = "kernel"):
     """One decode step over paged KV for homogeneous attention models.
 
-    k/v_pages: (L, P, T, K, hd).  New K/V are scattered into the page that
+    k/v_pages: (L, P, K, T, hd).  New K/V are scattered into the page that
     ElasticKV mapped for each sequence's position (= its current length);
     attention runs through the E-Attention Pallas kernel per layer.  Returns
     (logits, k_pages, v_pages, lengths+1) — lengths advance on device so the
@@ -1750,7 +1771,7 @@ def _paged_decode_step(params, cfg: ModelConfig, token, tables, lengths,
     from repro.models import layers as Lmod
 
     B = token.shape[0]
-    T = k_pages.shape[2]
+    T = k_pages.shape[3]
     pos = lengths  # next position = current per-sequence length
     x = params["embed"][token][:, None, :]  # (B, 1, D)
     seg_params = params["segments"][0]
@@ -1772,8 +1793,8 @@ def _paged_decode_step(params, cfg: ModelConfig, token, tables, lengths,
         rp = mrope if cfg.mrope_sections else positions
         q = cmod.apply_rope(q, rp, cfg.rope_theta, cfg.mrope_sections)
         knew = cmod.apply_rope(knew, rp, cfg.rope_theta, cfg.mrope_sections)
-        kp_l = kp_l.at[pbn, slot].set(knew[:, 0])
-        vp_l = vp_l.at[pbn, slot].set(vnew[:, 0])
+        kp_l = kp_l.at[pbn, :, slot].set(knew[:, 0])
+        vp_l = vp_l.at[pbn, :, slot].set(vnew[:, 0])
         attn_fn = (kops.paged_attention if attn == "kernel"
                    else kops.paged_attention_ref)
         o = attn_fn(q[:, 0], kp_l, vp_l, tables, lengths + 1)

@@ -34,8 +34,8 @@ PAGED_CASES = [
 def test_paged_attention_matches_ref(B, H, K, hd, T, P, N, dtype):
     ks = jax.random.split(jax.random.fold_in(KEY, hash((B, H, K, hd, T)) & 0x7FFFFFFF), 5)
     q = jax.random.normal(ks[0], (B, H, hd), jnp.float32).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (P, T, K, hd), jnp.float32).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (P, T, K, hd), jnp.float32).astype(dtype)
+    k_pages = jax.random.normal(ks[1], (P, K, T, hd), jnp.float32).astype(dtype)
+    v_pages = jax.random.normal(ks[2], (P, K, T, hd), jnp.float32).astype(dtype)
     tables = jax.random.randint(ks[3], (B, N), 0, P, dtype=jnp.int32)
     max_len = N * T
     lengths = jax.random.randint(ks[4], (B,), 1, max_len + 1, dtype=jnp.int32)
@@ -49,12 +49,12 @@ def test_paged_attention_matches_ref(B, H, K, hd, T, P, N, dtype):
 def test_paged_attention_single_token_context():
     """length=1: exactly one KV slot contributes."""
     q = jnp.ones((1, 2, 64))
-    k_pages = jax.random.normal(KEY, (4, 16, 2, 64))
-    v_pages = jax.random.normal(jax.random.fold_in(KEY, 1), (4, 16, 2, 64))
+    k_pages = jax.random.normal(KEY, (4, 2, 16, 64))
+    v_pages = jax.random.normal(jax.random.fold_in(KEY, 1), (4, 2, 16, 64))
     tables = jnp.array([[2, 0]], jnp.int32)
     lengths = jnp.array([1], jnp.int32)
     out = paged_attention(q, k_pages, v_pages, tables, lengths)
-    expect = v_pages[2, 0]  # softmax over one position = that position's V
+    expect = v_pages[2, :, 0]  # softmax over one position = that position's V
     assert jnp.allclose(out[0], expect, atol=1e-5)
 
 
@@ -62,8 +62,8 @@ def test_paged_attention_ignores_stale_pages():
     """Entries past `length` (and their page ids) must not affect output."""
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (2, 4, 64))
-    k_pages = jax.random.normal(ks[1], (8, 16, 2, 64))
-    v_pages = jax.random.normal(ks[2], (8, 16, 2, 64))
+    k_pages = jax.random.normal(ks[1], (8, 2, 16, 64))
+    v_pages = jax.random.normal(ks[2], (8, 2, 16, 64))
     t1 = jnp.array([[0, 1, 2], [3, 4, 5]], jnp.int32)
     t2 = jnp.array([[0, 1, 7], [3, 4, 6]], jnp.int32)  # tails differ
     lengths = jnp.array([20, 30], jnp.int32)  # only first 2 blocks live

@@ -45,8 +45,6 @@ SNIPPET = textwrap.dedent("""
         mod = HloModule(comp.as_text(), trip_hints=[L])
         c = mod.entry_cost()
         ca = comp.cost_analysis()
-        if isinstance(ca, list):  # jax <= 0.4.x: one dict per device
-            ca = ca[0]
         out[name] = {"flops": c.flops, "coll": c.collective_bytes,
                      "xla_flops": ca.get("flops")}
     print("RESULT" + json.dumps(out))
