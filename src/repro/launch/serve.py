@@ -73,6 +73,11 @@ def _print_ttft_breakdown(records):
     print(f"  {'ttft':8s} {ttft_total / n:8.3f}s "
           f"{percentile(sorted(r.ttft for r in records), 0.95):8.3f}s "
           f"{1.0:6.1%}")
+    # the program's own first-token stamp, from the serve's start: the
+    # phases above plus the gateway's host time, less the queue
+    xs = sorted(r.first_token_s for r in records)
+    print(f"  {'first':8s} {sum(xs) / n:8.3f}s {percentile(xs, 0.95):8.3f}s "
+          f"{'':>7s}")
 
 
 def _export_obs(tracer, args, extra_summary=None):
@@ -257,11 +262,16 @@ def main(argv=None) -> Served:
     # obs plane (DESIGN.md §18): one tracer across the engines and the
     # gateway — engine spans stamp perf_counter walls, request span
     # families ride the virtual trace clock, each on its own track
-    tracer = None
+    tracer, uninstall_jit = None, lambda: None
     if args.trace_out or args.metrics_out:
         from repro.obs import FlightRecorder, Tracer
+        from repro.obs import jit as obs_jit
 
-        tracer = Tracer(flight=FlightRecorder())
+        # the spans also enter a profiler trace, if one is running, and
+        # JAX's compile steps are spans of their own
+        tracer = Tracer(flight=FlightRecorder(),
+                        annotate=jax.profiler.TraceAnnotation)
+        uninstall_jit = obs_jit.install(tracer)
 
     names = args.models.split(",")
     cfgs = model_configs(args)
@@ -323,6 +333,7 @@ def main(argv=None) -> Served:
                       f"recoveries={fsum['engine_recoveries']} "
                       f"redriven={fsum['requests_redriven']}")
         _print_ttft_breakdown(sink.records)
+        uninstall_jit()
         _export_obs(tracer, args, extra_summary=s)
         for eng in engines:
             eng.close()
@@ -343,7 +354,7 @@ def main(argv=None) -> Served:
         shape = dataclasses.replace(SHAPES["train_4k"], seq_len=args.prompt_len,
                                     global_batch=2, kind="prefill")
         batch = model.make_batch(jax.random.PRNGKey(i), shape)
-        _, prefill_s, decode_s = generate(inst, batch, args.gen_tokens)
+        _, prefill_s, decode_s, _ = generate(inst, batch, args.gen_tokens)
         inst.finish()
         stats = engine.last_load
         pf = (f" prefetched={stats.bytes_prefetched/1e6:.1f}MB"
@@ -353,6 +364,7 @@ def main(argv=None) -> Served:
               f"(modeled load {rep.load_seconds*1e3:6.1f}ms, wall {load_s:.2f}s) "
               f"prefill {prefill_s:.2f}s decode {decode_s/args.gen_tokens*1e3:.0f}ms/tok "
               f"pool_free={engine.store.free_bytes()/1e6:.0f}MB{pf}")
+    uninstall_jit()
     _export_obs(tracer, args)
     engine.close()
     return Served(engines)

@@ -9,6 +9,14 @@ tracer's:
     explicit virtual trace-clock timestamps — durations it PRICED, never
     measured — so replays at a fixed seed produce bit-identical traces.
 
+The profiler bridge: a tracer built with ``annotate=`` (the real plane
+passes ``jax.profiler.TraceAnnotation``; this module never imports jax)
+opens ``annotate(name)`` around every live span, so the program's spans
+also land in the profiler's trace, on the device trace's clock, where idle
+gaps of the device can be put down to them.  Live spans record the thread
+that closed them (``SpanEvent.thread``): a request's spans are those its
+serving thread closed inside its ``serve`` span (``repro.obs``).
+
 Near-zero overhead when disabled: ``NULL_TRACER`` is a stateless singleton
 whose methods return cached constants, so the hot decode path pays one
 attribute load and a branch (``if tracer.enabled:``) and allocates nothing.
@@ -28,7 +36,7 @@ from __future__ import annotations
 
 import threading
 import time as _time
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, ContextManager, NamedTuple, Optional
 
 from repro.obs.ring import BoundedLog
 
@@ -43,6 +51,7 @@ class SpanEvent(NamedTuple):
     end: Optional[float]
     cat: str = "phase"
     args: Optional[dict] = None
+    thread: Optional[int] = None  # the closing thread's ident (live spans)
 
     @property
     def duration(self) -> float:
@@ -71,25 +80,30 @@ class Tracer:
     ``clock`` is any zero-arg float callable (defaults to
     ``time.perf_counter``); the modeled plane never calls it — it emits
     explicit virtual timestamps — so a sim tracer works with the default.
+    ``annotate``, when given, is a factory of context managers, one opened
+    around each live span under the span's name (the profiler bridge).
     """
 
     enabled = True
 
     def __init__(self, *, clock: Callable[[], float] = _time.perf_counter,
                  max_events: int = 65536,
-                 flight: Optional[FlightRecorder] = None):
+                 flight: Optional[FlightRecorder] = None,
+                 annotate: Optional[Callable[[str], ContextManager]] = None):
         self.clock = clock
         self.flight = flight
+        self.annotate = annotate
         self._events: BoundedLog = BoundedLog(max_events)
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------- emission
     def emit(self, name: str, begin: float, end: float, *,
              track: str = "main", cat: str = "phase",
-             args: Optional[dict] = None) -> None:
+             args: Optional[dict] = None,
+             thread: Optional[int] = None) -> None:
         """Record a complete span with explicit timestamps (the modeled
-        plane's path, and the real plane's when it already measured)."""
-        ev = SpanEvent(name, track, begin, end, cat, args)
+        plane's path, and listeners that receive a finished interval)."""
+        ev = SpanEvent(name, track, begin, end, cat, args, thread)
         with self._lock:
             self._events.append(ev)
 
@@ -104,7 +118,8 @@ class Tracer:
 
     def span(self, name: str, *, track: str = "main", cat: str = "phase",
              args: Optional[dict] = None) -> "_LiveSpan":
-        """Context manager measuring [enter, exit] on the injected clock."""
+        """Context manager measuring [enter, exit] on the injected clock,
+        inside ``annotate(name)`` when the tracer has one."""
         return _LiveSpan(self, name, track, cat, args)
 
     def record_fault(self, reason: str, ts: Optional[float] = None, *,
@@ -137,7 +152,8 @@ class Tracer:
 class _LiveSpan:
     """An open span: stamps the clock at enter/exit and emits on exit."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_cat", "_args", "_begin")
+    __slots__ = ("_tracer", "_name", "_track", "_cat", "_args", "_begin",
+                 "_ann")
 
     def __init__(self, tracer: Tracer, name: str, track: str, cat: str,
                  args: Optional[dict]):
@@ -147,14 +163,22 @@ class _LiveSpan:
         self._cat = cat
         self._args = args
         self._begin = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_LiveSpan":
+        if self._tracer.annotate is not None:
+            self._ann = self._tracer.annotate(self._name)
+            self._ann.__enter__()
         self._begin = self._tracer.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self._tracer.emit(self._name, self._begin, self._tracer.clock(),
-                          track=self._track, cat=self._cat, args=self._args)
+        end = self._tracer.clock()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._tracer.emit(self._name, self._begin, end, track=self._track,
+                          cat=self._cat, args=self._args,
+                          thread=threading.get_ident())
         return False
 
 
@@ -186,7 +210,7 @@ class _NullTracer:
     flight = None
 
     def emit(self, name, begin, end, *, track="main", cat="phase",
-             args=None) -> None:
+             args=None, thread=None) -> None:
         return None
 
     def instant(self, name, ts=None, *, track="main", cat="instant",

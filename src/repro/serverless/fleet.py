@@ -60,8 +60,8 @@ from repro.core.trace import (Request, SimModel, synthetic_tensor_sizes,
 from repro.models.tensors import ModelSpec, TensorRecord, VariantSpec
 from repro.obs import NULL_TRACER, BoundedLog, trace_request
 from repro.stats import FleetStats, ModeledFaultStats
-from repro.serverless.gateway import (MetricsSink, TTFTRecord, generate,
-                                      make_prefill_batch)
+from repro.serverless.gateway import (TRACK, MetricsSink, TTFTRecord,
+                                      generate, make_prefill_batch)
 from repro.serverless.lifecycle import LifecycleManager, make_keep_alive
 from repro.serverless.workload import FaultEvent, PressureEvent
 
@@ -272,6 +272,9 @@ class FleetGateway:
         # the serve seam's side channel carrying each phase's cost-model
         # prediction into the request's spans (the span/cost cross-check)
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        # live spans on the wall clock (serve, route): the modeled plane
+        # reads no wall clock, so `ModeledFleetGateway` turns them off
+        self._live = self.tracer
         self._last_preds: Optional[dict] = None
         self.nodes = [EngineNode(e, prefetch=prefetch) for e in engines]
         ids = [n.device_id for n in self.nodes]
@@ -667,7 +670,19 @@ class FleetGateway:
             now = req.time
             self._arrivals += 1
             pi = self._advance(now, press, pi)
-            model = req.model_id
+            # a request's spans are those its thread closes inside this one
+            # (repro.obs); `cold` is known once the request is routed
+            args = {"rid": len(self.sink.records), "model": req.model_id}
+            with self._live.span("serve", track=TRACK, args=args):
+                self._serve_one(req, now, args)
+        return self.sink
+
+    def _serve_one(self, req: Request, now: float, args: dict):
+        """Route, admit and serve one arrival; `args` are its `serve`
+        span's."""
+        t_start = _time.perf_counter()
+        model = req.model_id
+        with self._live.span("route", track=TRACK):
             self.lifecycle.observe_arrival(model, now)
             self._armed.pop(model, None)  # the arrival voids the prediction
             if any(n.failed for n in self.nodes):
@@ -700,50 +715,51 @@ class FleetGateway:
                     self.prewarm_hits += 1
                     self.log.append(("prewarm-hit", round(now, 6), model,
                                      node.device_id, round(eta, 6)))
-            self.lifecycle.on_start(model, now, warm=not cold)
-            queue_s = max(0.0, node.busy_until - now)
-            rec, service_s = self._serve(node, req, now, cold, queue_s)
-            t_end = now + queue_s + service_s
-            node.busy_until = t_end
-            node.inflight = [e for e in node.inflight if e["t_end"] > now]
-            node.inflight.append({"t_end": t_end, "model": model,
-                                  "kv_bytes": 0.0, "model_bytes": 0.0,
-                                  **(self._migration_meta(req) or {})})
-            self.decisions.append((round(now, 6), model, node.device_id,
-                                   cold, round(queue_s, 6)))
-            self.sink.add(rec)
-            if self.tracer.enabled:
-                # span-accounting identity (DESIGN.md §18): the parent span
-                # is the REPORTED ttft, children are the phase fields — a
-                # phase folded into the sum without a span shows up as
-                # unattributed time, and check_bench fails the entry
-                trace_request(
-                    self.tracer, rid=len(self.sink.records) - 1,
-                    model_id=model, arrival=now, ttft=rec.ttft,
-                    phases=[("queue", rec.queue_s), ("init", rec.init_s),
-                            ("load", rec.load_s),
-                            ("profile", rec.profile_s),
-                            ("prefill", rec.prefill_s)],
-                    decode_s=rec.decode_s, cold=cold,
-                    engine=node.device_id, preds=self._last_preds)
-            # post-serve keep-alive: the warm entry was popped at admission,
-            # so a stale warm-until can never truncate the fresh TTL (the
-            # same idle_epoch-style guard the Gateway and sim carry)
-            ttl = self.lifecycle.on_idle(model, t_end)
-            if ttl > 0:
-                node.engine.retain(model)
-                node.warm[model] = t_end + ttl
-            else:
-                self.lifecycle.on_expire(model, t_end)
-                node.engine.release(model)
-                self._arm_prewarm(model, t_end)
-        return self.sink
+        args["cold"] = cold
+        self.lifecycle.on_start(model, now, warm=not cold)
+        queue_s = max(0.0, node.busy_until - now)
+        rec, service_s = self._serve(node, req, now, cold, queue_s, t_start)
+        t_end = now + queue_s + service_s
+        node.busy_until = t_end
+        node.inflight = [e for e in node.inflight if e["t_end"] > now]
+        node.inflight.append({"t_end": t_end, "model": model,
+                              "kv_bytes": 0.0, "model_bytes": 0.0,
+                              **(self._migration_meta(req) or {})})
+        self.decisions.append((round(now, 6), model, node.device_id,
+                               cold, round(queue_s, 6)))
+        self.sink.add(rec)
+        if self.tracer.enabled:
+            # span-accounting identity (DESIGN.md §18): the parent span
+            # is the REPORTED ttft, children are the phase fields — a
+            # phase folded into the sum without a span shows up as
+            # unattributed time, and check_bench fails the entry
+            trace_request(
+                self.tracer, rid=len(self.sink.records) - 1,
+                model_id=model, arrival=now, ttft=rec.ttft,
+                phases=[("queue", rec.queue_s), ("init", rec.init_s),
+                        ("load", rec.load_s),
+                        ("profile", rec.profile_s),
+                        ("prefill", rec.prefill_s)],
+                decode_s=rec.decode_s, cold=cold,
+                engine=node.device_id, preds=self._last_preds)
+        # post-serve keep-alive: the warm entry was popped at admission,
+        # so a stale warm-until can never truncate the fresh TTL (the
+        # same idle_epoch-style guard the Gateway and sim carry)
+        ttl = self.lifecycle.on_idle(model, t_end)
+        if ttl > 0:
+            node.engine.retain(model)
+            node.warm[model] = t_end + ttl
+        else:
+            self.lifecycle.on_expire(model, t_end)
+            node.engine.release(model)
+            self._arm_prewarm(model, t_end)
 
     # ----------------------------------------------------------- serve seam
     def _serve(self, node: EngineNode, req: Request, now: float, cold: bool,
-               queue_s: float) -> tuple[TTFTRecord, float]:
+               queue_s: float, t_start: float) -> tuple[TTFTRecord, float]:
         """Real-plane serve on the routed engine: measured phase walls (the
-        single-engine Gateway's split), virtual trace clock for queueing."""
+        single-engine Gateway's split), virtual trace clock for queueing.
+        `t_start` is the wall at which the gateway took the request."""
         eng = node.engine
         t0 = _time.perf_counter()
         rep = submit_load(eng, LoadRequest(req.model_id, now=now))
@@ -751,17 +767,21 @@ class FleetGateway:
         stats = eng.last_load
         load_s = max(0.0, load_s - stats.init_seconds
                      - stats.profile_seconds)
-        inst = eng.start_instance(req.model_id, num_pages=self.num_pages)
-        batch = make_prefill_batch(eng, req.model_id, self.prompt_len,
-                                   next(self._req_seq))
-        tokens, prefill_s, decode_s = generate(inst, batch, self.gen_tokens)
+        with self._live.span("start_instance", track=TRACK):
+            inst = eng.start_instance(req.model_id, num_pages=self.num_pages)
+        with self._live.span("make_prefill_batch", track=TRACK):
+            batch = make_prefill_batch(eng, req.model_id, self.prompt_len,
+                                       next(self._req_seq))
+        tokens, prefill_s, decode_s, first = generate(inst, batch,
+                                                      self.gen_tokens)
         inst.finish()
         service_s = _time.perf_counter() - t0
         rec = TTFTRecord(
             model_id=req.model_id, arrival=now, cold=cold, queue_s=queue_s,
             init_s=stats.init_seconds, load_s=load_s,
             profile_s=stats.profile_seconds, prefill_s=prefill_s,
-            decode_s=decode_s, prefetched=stats.bytes_prefetched > 0,
+            decode_s=decode_s, first_token_s=first - t_start,
+            prefetched=stats.bytes_prefetched > 0,
             bytes_from_store=stats.bytes_store, tokens=tokens)
         # span/cost cross-check: the measured load wall vs the cost plane's
         # tiered price for the same bytes (the only phase both planes state)
@@ -873,6 +893,7 @@ class ModeledFleetGateway(FleetGateway):
                          policy=policy, migrate=migrate,
                          migrate_replay_tokens=migrate_replay_tokens,
                          tracer=tracer)
+        self._live = NULL_TRACER
         self._sim = sims
 
     def _migration_meta(self, req: Request) -> dict:
@@ -886,7 +907,8 @@ class ModeledFleetGateway(FleetGateway):
                 "model_bytes": float(m.bytes)}
 
     def _serve(self, node: EngineNode, req: Request, now: float, cold: bool,
-               queue_s: float) -> tuple[TTFTRecord, float]:
+               queue_s: float, t_start: float) -> tuple[TTFTRecord, float]:
+        del t_start  # modeled phases take no wall clock
         m = self._sim[req.model_id]
         eng = node.engine
         start = now + queue_s

@@ -33,6 +33,11 @@ from repro.serverless.lifecycle import LifecycleManager, make_keep_alive
 from repro.serverless.workload import PressureEvent
 
 
+#: The tracer track of the real plane's per-request spans: ``serve`` and
+#: the gateway's steps inside it, and `generate`'s three parts.
+TRACK = "serve"
+
+
 @dataclass(frozen=True)
 class TTFTRecord:
     """One admitted request's phase breakdown (seconds)."""
@@ -46,6 +51,9 @@ class TTFTRecord:
     profile_s: float = 0.0
     prefill_s: float = 0.0
     decode_s: float = 0.0
+    # from the serve's start (the gateway took the request) to the first
+    # token on the device: the queue is not in it, routing is
+    first_token_s: float = 0.0
     joined: bool = False
     prefetched: bool = False
     bytes_from_store: int = 0
@@ -149,26 +157,34 @@ def make_prefill_batch(engine, model_id: str, prompt_len: int, seed: int):
 def generate(inst, batch, gen_tokens: int):
     """Prefill `batch` on `inst`, then greedy-decode `gen_tokens` more.
 
-    Returns ``(tokens, prefill_s, decode_s)``: row 0's tokens, and the two
-    phase walls, each stopped only once its last token is on the device —
-    dispatch is asynchronous, so a clock stopped at the enqueue would
-    measure the host alone."""
+    Returns ``(tokens, prefill_s, decode_s, first_token)``: row 0's tokens;
+    the two phase walls, each stopped only once its last token is on the
+    device — dispatch is asynchronous, so a clock stopped at the enqueue
+    would measure the host alone; and the ``perf_counter`` wall at which
+    the first token was on the device.  On the engine's tracer the three
+    parts are the spans ``prefill`` (the interval of prefill_s),
+    ``decode`` (of decode_s) and ``generate.tail`` (the tokens stacked and
+    copied to the host)."""
     import jax.numpy as jnp
     import numpy as np
 
-    t1 = _time.perf_counter()
-    tok = jnp.argmax(inst.prefill(batch), -1).astype(jnp.int32)
-    tok.block_until_ready()
-    prefill_s = _time.perf_counter() - t1
+    tracer = inst.engine.tracer
+    with tracer.span("prefill", track=TRACK):
+        t1 = _time.perf_counter()
+        tok = jnp.argmax(inst.prefill(batch), -1).astype(jnp.int32)
+        tok.block_until_ready()
+        first = _time.perf_counter()
     toks = [tok]
-    t2 = _time.perf_counter()
-    for _ in range(gen_tokens):
-        tok = jnp.argmax(inst.decode(tok), -1).astype(jnp.int32)
-        toks.append(tok)
-    tok.block_until_ready()
-    decode_s = _time.perf_counter() - t2
-    tokens = tuple(int(t[0]) for t in np.asarray(jnp.stack(toks)))
-    return tokens, prefill_s, decode_s
+    with tracer.span("decode", track=TRACK):
+        t2 = _time.perf_counter()
+        for _ in range(gen_tokens):
+            tok = jnp.argmax(inst.decode(tok), -1).astype(jnp.int32)
+            toks.append(tok)
+        tok.block_until_ready()
+        decode_s = _time.perf_counter() - t2
+    with tracer.span("generate.tail", track=TRACK):
+        tokens = tuple(int(t[0]) for t in np.asarray(jnp.stack(toks)))
+    return tokens, first - t1, decode_s, first
 
 
 class Gateway:
@@ -284,8 +300,8 @@ class Gateway:
                 self.engine.prefetch(next_model[i])
             inst = self.engine.start_instance(model, num_pages=self.num_pages)
             batch = self._prefill_batch(model, i)
-            tokens, prefill_s, decode_s = generate(inst, batch,
-                                                   self.gen_tokens)
+            tokens, prefill_s, decode_s, first = generate(inst, batch,
+                                                          self.gen_tokens)
             inst.finish()
             # measured service wall occupies the virtual server on the
             # trace clock (decode included: the instance holds its slot
@@ -299,6 +315,7 @@ class Gateway:
                 init_s=stats.init_seconds, load_s=load_s,
                 profile_s=stats.profile_seconds,
                 prefill_s=prefill_s, decode_s=decode_s,
+                first_token_s=first - t0,
                 prefetched=stats.bytes_prefetched > 0,
                 bytes_from_store=stats.bytes_store, tokens=tokens)
             self.sink.add(rec)

@@ -547,8 +547,14 @@ class Prefetcher:
                 with eng._store_lock:
                     if (fp in eng.persistent_store
                             and fp not in eng.host_store):
-                        tb = _time.perf_counter() if tracer.enabled else 0.0
-                        arr = eng.host_store.fetch(fp)
+                        # worker-thread span: the tracer's lock makes its
+                        # emit safe against a concurrent load's spans
+                        with tracer.span("prefetch.promote",
+                                         track=getattr(eng, "_track",
+                                                       "prefetch"),
+                                         cat="prefetch",
+                                         args={"model": job.model_id}):
+                            arr = eng.host_store.fetch(fp)
                         job.promoted.append((fp, arr.nbytes))
                         job.tensors_promoted += 1
                         job.bytes_promoted += arr.nbytes
@@ -557,15 +563,6 @@ class Prefetcher:
                         # the partial read's bytes
                         self.bytes_promoted += arr.nbytes
                         self.promote_log.append((job.model_id, fp))
-                        if tracer.enabled:
-                            # worker-thread emit: the tracer's lock makes
-                            # this safe against a concurrent load's spans
-                            tracer.emit("prefetch.promote", tb,
-                                        _time.perf_counter(),
-                                        track=getattr(eng, "_track",
-                                                      "prefetch"),
-                                        cat="prefetch",
-                                        args={"model": job.model_id})
             except WorkerDeath:
                 # kills THIS worker: the job fails over (finally fires its
                 # event so joiners go inline) and the supervisor restarts
@@ -850,6 +847,17 @@ class Engine:
         overlap is the real prefetch join above, so it is not re-applied
         here.
         """
+        # the measured load wall beside the cost plane's tiered prediction
+        # (`pred`): the real-plane half of the span/cost cross-check (§18)
+        args = {"model": model_id}
+        with self.tracer.span("load", track=self._track, cat="engine",
+                              args=args):
+            report = self._load(model_id, now=now, overlap_s=overlap_s)
+            args["pred"] = report.load_seconds
+        return report
+
+    def _load(self, model_id: str, *, now: float,
+              overlap_s: float) -> LoadReport:
         reg = self.models[model_id]
         report = self.store.load_model(model_id, reg.records, now=now,
                                        overlap_s=overlap_s)
@@ -865,14 +873,11 @@ class Engine:
             # dead/failed/wedged job fails this load over to the inline path
             # (un-promoted tensors are still store-resolvable) instead of
             # wedging it (DESIGN.md §15).
-            tw = _time.perf_counter()
-            joined = job.done.wait(timeout=self.join_timeout_s)
-            stats.prefetch_wait_seconds = _time.perf_counter() - tw
-            if self.tracer.enabled:
-                self.tracer.emit("prefetch.join", tw,
-                                 tw + stats.prefetch_wait_seconds,
-                                 track=self._track, cat="prefetch",
-                                 args={"model": model_id})
+            with self.tracer.span("prefetch.join", track=self._track,
+                                  cat="prefetch", args={"model": model_id}):
+                tw = _time.perf_counter()
+                joined = job.done.wait(timeout=self.join_timeout_s)
+                stats.prefetch_wait_seconds = _time.perf_counter() - tw
             if not joined or job.failed:
                 self.fault_stats.join_failovers += 1
                 stats.prefetch_failover = True
@@ -921,13 +926,6 @@ class Engine:
         report.load_seconds = self.store.costs.load_time_tiered(
             report.bytes_from_host, report.bytes_from_store)
         self.last_load = stats
-        if self.tracer.enabled:
-            # measured load wall vs the cost plane's tiered prediction —
-            # the real-plane half of the span/cost cross-check (§18)
-            self.tracer.emit("load", t0, t0 + stats.total_seconds,
-                             track=self._track, cat="engine",
-                             args={"model": model_id,
-                                   "pred": report.load_seconds})
         return report
 
     def _load_tensors(self, reg: RegisteredModel, stats: DataLoadStats):
@@ -953,48 +951,46 @@ class Engine:
                            if r.fingerprint not in self.host_store
                            and r.fingerprint in self.persistent_store]
             if len(host_hits) + len(spilled) < len(to_move):
-                tm = _time.perf_counter()
-                params = self._init_params(reg)  # full materialization: once
-                with self._store_lock:
-                    stats.leaves_materialized = self.host_store.put_tree(
-                        reg.records, params)
-                stats.init_seconds = _time.perf_counter() - tm
-                del params
-                if self.tracer.enabled:
-                    self.tracer.emit("init", tm, tm + stats.init_seconds,
-                                     track=self._track, cat="engine",
-                                     args={"model": reg.model_id})
+                with self.tracer.span("init", track=self._track,
+                                      cat="engine",
+                                      args={"model": reg.model_id}):
+                    tm = _time.perf_counter()
+                    params = self._init_params(reg)  # full: once ever
+                    with self._store_lock:
+                        stats.leaves_materialized = self.host_store.put_tree(
+                            reg.records, params)
+                    stats.init_seconds = _time.perf_counter() - tm
+                    del params
             stats.tensors_host_hit = len(host_hits)
             stats.bytes_host_hit = sum(r.nbytes for r in host_hits)
             if spilled:
-                ts = _time.perf_counter()
-                retries0 = self.host_store.read_retries
-                quarantined: list[TensorRecord] = []
-                promoted_bytes = 0
-                for r in spilled:  # store_bw-limited promotion, pinned above
-                    try:
-                        with self._store_lock:
-                            self.host_store.fetch(r.fingerprint)
-                        promoted_bytes += r.nbytes
-                    except StoreError as e:
-                        # fetch already retried/backed-off and quarantined
-                        # the blob (DESIGN.md §15) — collect for the init_fn
-                        # fallback below instead of failing the load
-                        log.warning("store promote of %s (%s) unrecoverable "
-                                    "(%s: %s) — re-materializing",
-                                    r.name, r.fingerprint,
-                                    type(e).__name__, e)
-                        quarantined.append(r)
-                stats.store_retries = (self.host_store.read_retries
-                                       - retries0)
-                stats.store_seconds = _time.perf_counter() - ts
-                if self.tracer.enabled:
-                    self.tracer.emit("store.read", ts,
-                                     ts + stats.store_seconds,
-                                     track=self._track, cat="engine",
-                                     args={"model": reg.model_id,
-                                           "bytes": promoted_bytes,
-                                           "retries": stats.store_retries})
+                read = {"model": reg.model_id}
+                with self.tracer.span("store.read", track=self._track,
+                                      cat="engine", args=read):
+                    ts = _time.perf_counter()
+                    retries0 = self.host_store.read_retries
+                    quarantined: list[TensorRecord] = []
+                    promoted_bytes = 0
+                    for r in spilled:  # store_bw-limited, pinned above
+                        try:
+                            with self._store_lock:
+                                self.host_store.fetch(r.fingerprint)
+                            promoted_bytes += r.nbytes
+                        except StoreError as e:
+                            # fetch already retried/backed-off and
+                            # quarantined the blob (DESIGN.md §15) — collect
+                            # for the init_fn fallback below instead of
+                            # failing the load
+                            log.warning("store promote of %s (%s) "
+                                        "unrecoverable (%s: %s) — "
+                                        "re-materializing", r.name,
+                                        r.fingerprint, type(e).__name__, e)
+                            quarantined.append(r)
+                    stats.store_retries = (self.host_store.read_retries
+                                           - retries0)
+                    stats.store_seconds = _time.perf_counter() - ts
+                    read.update(bytes=promoted_bytes,
+                                retries=stats.store_retries)
                 stats.tensors_store = len(spilled) - len(quarantined)
                 stats.bytes_store = promoted_bytes
                 if quarantined:
@@ -1003,56 +999,55 @@ class Engine:
                     # still-resolvable leaves, only the quarantined ones
                     # (and nothing else) are re-stored
                     stats.tensors_quarantined = len(quarantined)
-                    tm = _time.perf_counter()
-                    params = self._init_params(reg)
-                    with self._store_lock:
-                        stats.leaves_materialized += self.host_store.put_tree(
-                            reg.records, params)
-                    stats.init_seconds += _time.perf_counter() - tm
-                    del params
+                    with self.tracer.span("init", track=self._track,
+                                          cat="engine",
+                                          args={"model": reg.model_id,
+                                                "reinit": len(quarantined)}):
+                        tm = _time.perf_counter()
+                        params = self._init_params(reg)
+                        with self._store_lock:
+                            stats.leaves_materialized += (
+                                self.host_store.put_tree(reg.records,
+                                                         params))
+                        stats.init_seconds += _time.perf_counter() - tm
+                        del params
                     stats.tensors_reinit = len(quarantined)
                     self.fault_stats.tensors_reinit += len(quarantined)
-                    if self.tracer.enabled:
-                        self.tracer.emit("init", tm, _time.perf_counter(),
-                                         track=self._track, cat="engine",
-                                         args={"model": reg.model_id,
-                                               "reinit": len(quarantined)})
-            tt = _time.perf_counter()
-            with self._store_lock:  # snapshot host buffers for the pipeline
-                items = [(r.fingerprint, self.host_store.get(r.fingerprint))
-                         for r in to_move]
-            # bounded whole-transfer retry: chunk-level errors retry inside
-            # ChunkedTransfer; a TransferTimeout (or exhausted chunk budget)
-            # re-runs the pipeline once before the load truly fails
-            h2d_snapshot = (stats.tensors_h2d, stats.bytes_h2d,
-                            stats.chunks_h2d)
-            try:
-                moved = self._xfer.transfer(items, stats)
-            except TransferError as e:
-                log.warning("chunked transfer failed (%s: %s) — retrying "
-                            "once", type(e).__name__, e)
-                (stats.tensors_h2d, stats.bytes_h2d,
-                 stats.chunks_h2d) = h2d_snapshot  # don't double-count
-                moved = self._xfer.transfer(items, stats)
-            stats.transfer_seconds = _time.perf_counter() - tt
-            if self.tracer.enabled:
-                self.tracer.emit("h2d", tt, tt + stats.transfer_seconds,
-                                 track=self._track, cat="engine",
-                                 args={"model": reg.model_id,
-                                       "bytes": stats.bytes_h2d,
-                                       "chunks": stats.chunks_h2d})
+            h2d = {"model": reg.model_id}
+            with self.tracer.span("h2d", track=self._track, cat="engine",
+                                  args=h2d):
+                tt = _time.perf_counter()
+                with self._store_lock:  # snapshot host buffers to move
+                    items = [(r.fingerprint,
+                              self.host_store.get(r.fingerprint))
+                             for r in to_move]
+                # bounded whole-transfer retry: chunk-level errors retry
+                # inside ChunkedTransfer; a TransferTimeout (or exhausted
+                # chunk budget) re-runs the pipeline once before the load
+                # truly fails
+                h2d_snapshot = (stats.tensors_h2d, stats.bytes_h2d,
+                                stats.chunks_h2d)
+                try:
+                    moved = self._xfer.transfer(items, stats)
+                except TransferError as e:
+                    log.warning("chunked transfer failed (%s: %s) — "
+                                "retrying once", type(e).__name__, e)
+                    (stats.tensors_h2d, stats.bytes_h2d,
+                     stats.chunks_h2d) = h2d_snapshot  # don't double-count
+                    moved = self._xfer.transfer(items, stats)
+                stats.transfer_seconds = _time.perf_counter() - tt
+                h2d.update(bytes=stats.bytes_h2d, chunks=stats.chunks_h2d)
             self._tensors.update(moved)
         if to_move or reg.model_id not in self._params_cache:
             # assemble the param tree from resident buffers (no copies) —
             # measured as the Profile phase of the TTFT split
-            tp = _time.perf_counter()
-            self._params_cache[reg.model_id] = jax.tree.unflatten(
-                reg.treedef, [self._tensors[r.fingerprint] for r in reg.records])
-            stats.profile_seconds = _time.perf_counter() - tp
-            if self.tracer.enabled:
-                self.tracer.emit("profile", tp, tp + stats.profile_seconds,
-                                 track=self._track, cat="engine",
-                                 args={"model": reg.model_id})
+            with self.tracer.span("profile", track=self._track,
+                                  cat="engine", args={"model": reg.model_id}):
+                tp = _time.perf_counter()
+                self._params_cache[reg.model_id] = jax.tree.unflatten(
+                    reg.treedef,
+                    [self._tensors[r.fingerprint] for r in reg.records])
+                stats.profile_seconds = _time.perf_counter() - tp
 
     def _init_params(self, reg: RegisteredModel):
         """Run the model's `init_fn` on this engine's device."""
@@ -1437,8 +1432,16 @@ class Engine:
         are FUSED into a single dispatch (their batches concatenate along B;
         per-row numerics are unchanged).  Returns per-instance logits."""
         # hot path: with tracing disabled this is one attribute load and a
-        # branch at entry/exit, zero allocations (tests/test_obs.py pins it)
-        tb = _time.perf_counter() if self.tracer.enabled else 0.0
+        # branch, zero allocations (tests/test_obs.py pins the idiom)
+        if self.tracer.enabled:
+            with self.tracer.span("decode.step", track=self._track,
+                                  cat="decode",
+                                  args={"instances": len(steps)}):
+                return self._decode_many(steps)
+        return self._decode_many(steps)
+
+    def _decode_many(self, steps: Sequence[tuple["Instance", jnp.ndarray]]
+                     ) -> list[jnp.ndarray]:
         out: list[Optional[jnp.ndarray]] = [None] * len(steps)
         groups: dict[tuple, list[int]] = {}
         for i, (inst, _tok) in enumerate(steps):
@@ -1456,10 +1459,6 @@ class Engine:
             out_slices = self._decode_fused([steps[i] for i in idxs])
             for i, logits in zip(idxs, out_slices):
                 out[i] = logits
-        if self.tracer.enabled:
-            self.tracer.emit("decode.step", tb, _time.perf_counter(),
-                             track=self._track, cat="decode",
-                             args={"instances": len(steps)})
         return out  # type: ignore[return-value]
 
     def _decode_fused(self, group: list[tuple["Instance", jnp.ndarray]]
@@ -1565,10 +1564,12 @@ class Instance:
     # ---------------------------------------------------------------- prefill
     def prefill(self, batch: dict, *, lengths: Optional[Sequence[int]] = None
                 ) -> jnp.ndarray:
-        """Traced entry point — see `_prefill_impl` for the semantics."""
+        """Traced entry point — see `_prefill_impl` for the semantics.  The
+        span ends when the prefill is dispatched, not when it has run."""
         eng = self.engine
         if eng.tracer.enabled:
-            with eng.tracer.span("prefill", track=eng._track, cat="engine",
+            with eng.tracer.span("prefill.dispatch", track=eng._track,
+                                 cat="engine",
                                  args={"model": self.reg.model_id}):
                 return self._prefill_impl(batch, lengths=lengths)
         return self._prefill_impl(batch, lengths=lengths)

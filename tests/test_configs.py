@@ -26,6 +26,7 @@ SLOW_TESTS = {
     "tests/test_launchers.py",     # launch subprocesses
     "tests/test_migration.py",     # cross-engine decode handoff (jit)
     "tests/test_models.py",        # per-arch forward numerics
+    "tests/test_obs_live.py",      # real-plane spans, JAX compile listeners
     "tests/test_roofline.py",      # analysis over real configs
     "tests/test_system.py",        # end-to-end serve scenarios
     "tests/test_train.py",         # training-step compiles

@@ -577,7 +577,8 @@ def test_decode_loop_passes_d2h_transfer_guard():
 def test_real_plane_trace_exports_loadable_perfetto_json(tmp_path):
     """DESIGN.md §18: an Engine with a tracer attached emits the full
     cold-start span family on perf_counter walls — store.read, per-chunk
-    h2d, init, profile, load, prefill, fused decode steps — and the export
+    h2d, init, profile, load, prefill.dispatch, fused decode steps — and
+    the export
     is valid Trace Event Format JSON (what ui.perfetto.dev loads)."""
     import json
 
@@ -604,7 +605,7 @@ def test_real_plane_trace_exports_loadable_perfetto_json(tmp_path):
     for ev in tracer.events():
         by_name.setdefault(ev.name, []).append(ev)
     for name in ("store.read", "h2d", "h2d.chunk", "init", "profile",
-                 "load", "prefill"):
+                 "load", "prefill.dispatch"):
         assert name in by_name, f"cold-start phase {name} never traced"
     assert len(by_name["decode.step"]) == 3
     cold, reload_ = by_name["load"]

@@ -73,9 +73,53 @@ def test_tracer_span_uses_injected_clock():
     tr.instant("crash")  # third tick
     (span, inst) = tr.events()
     assert span == SpanEvent("load", "eng:0", 10.0, 10.5, "engine",
-                             {"model": "m"})
+                             {"model": "m"}, threading.get_ident())
     assert span.duration == 0.5
     assert inst.begin == 11.0 and inst.end is None and inst.duration == 0.0
+
+
+def test_tracer_opens_annotate_around_live_spans():
+    """The profiler bridge: each live span runs inside `annotate(name)`,
+    entered before the span's clock starts and left after it stops, so
+    the profiler's interval holds the tracer's; emits are not annotated."""
+    log = []
+    ticks = iter([1.0, 2.0, 3.0, 4.0])
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    def clock():
+        t = next(ticks)
+        log.append(("clock", t))
+        return t
+
+    tr = Tracer(clock=clock, annotate=Ann)
+    with tr.span("serve"):
+        with tr.span("route"):
+            pass
+    tr.emit("req", 0.0, 1.0)  # a finished interval: nothing to annotate
+    assert log == [("enter", "serve"), ("clock", 1.0), ("enter", "route"),
+                   ("clock", 2.0), ("clock", 3.0), ("exit", "route"),
+                   ("clock", 4.0), ("exit", "serve")]
+    assert [(e.name, e.begin, e.end) for e in tr.events()] == [
+        ("route", 2.0, 3.0), ("serve", 1.0, 4.0), ("req", 0.0, 1.0)]
+    # a span that raises still leaves its annotation and is recorded
+    log.clear()
+    ticks = iter([5.0, 6.0])
+    with pytest.raises(ValueError):
+        with tr.span("load"):
+            raise ValueError
+    assert log[0] == ("enter", "load") and log[-1] == ("exit", "load")
+    # the disabled tracer has no factory to call
+    assert not hasattr(NULL_TRACER, "annotate")
+    assert NULL_TRACER.span("serve") is NULL_TRACER.span("route")
 
 
 def test_tracer_emit_takes_explicit_virtual_timestamps():
@@ -374,27 +418,9 @@ def test_metrics_registry_instruments_and_snapshot():
     reg.counter("loads").inc()
     reg.counter("loads").inc(2)
     assert reg.counter("loads") is reg.counter("loads")  # get-or-create
-    reg.gauge("pool_bytes").set(7.5)
-    h = reg.histogram("ttft")
-    for v in [1.0, 2.0, 3.0, 4.0]:
-        h.observe(v)
+    reg.counter("evictions").inc(0)
     snap = reg.snapshot().as_dict()
-    assert snap["counters"] == {"loads": 3}
-    assert snap["gauges"] == {"pool_bytes": 7.5}
-    ts = snap["histograms"]["ttft"]
-    assert ts["count"] == 4 and ts["sum"] == 10.0 and ts["mean"] == 2.5
-    # histogram percentiles use THE shared convention
-    assert h.percentile(0.5) == percentile([1.0, 2.0, 3.0, 4.0], 0.5)
-    assert ts["max"] == 4.0
-
-
-def test_histogram_reservoir_drops_oldest_keeps_exact_count():
-    reg = MetricsRegistry()
-    h = reg.histogram("x", max_samples=4)
-    for v in range(10):
-        h.observe(float(v))
-    assert h.count == 10 and h.sum == 45.0  # exact despite the bound
-    assert h.percentile(0.99) == 9.0  # newest window survives
+    assert snap == {"counters": {"evictions": 0, "loads": 3}}
 
 
 def test_registry_absorbs_legacy_nested_counter_dicts():
