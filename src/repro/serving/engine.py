@@ -1565,7 +1565,9 @@ class Instance:
     def prefill(self, batch: dict, *, lengths: Optional[Sequence[int]] = None
                 ) -> jnp.ndarray:
         """Traced entry point — see `_prefill_impl` for the semantics.  The
-        span ends when the prefill is dispatched, not when it has run."""
+        forward is one compiled program per (model config, prompt shape),
+        shared by every instance.  The span ends when the prefill is
+        dispatched, not when it has run."""
         eng = self.engine
         if eng.tracer.enabled:
             with eng.tracer.span("prefill.dispatch", track=eng._track,
@@ -1582,6 +1584,10 @@ class Instance:
         mixed-length batches; positions past a sequence's length hold padding
         whose K/V the paged kernel masks out.  Returns logits at each
         sequence's LAST REAL position, (B, V).
+
+        The forward is `_prefill_forward`, one compiled program per (model
+        config, cache capacity, batch shapes): a new instance of a model, or
+        other lengths at the same padded shape, reuse it untraced.
         """
         params = self.engine.params_of(self.reg.model_id)
         tokens = batch["tokens"]
@@ -1589,13 +1595,13 @@ class Instance:
         lens = (np.full((B,), S, np.int64) if lengths is None
                 else np.asarray(lengths, np.int64))
         assert lens.shape == (B,) and lens.min() >= 1 and lens.max() <= S
-        cap = -(-S // self.kv.block_tokens) * self.kv.block_tokens
-        logits, cache = self.model.prefill(params, batch,
-                                           cache_cap=max(cap, S),
-                                           remat=False)
-        last = logits[jnp.arange(B), jnp.asarray(lens - 1)]
+        T = self.kv.block_tokens
+        cap = -(-S // T) * T
         self._host_lens = lens.copy()
         self._lengths = jnp.asarray(lens, jnp.int32)
+        last, cache = _prefill_forward(params, batch, self._lengths,
+                                       self.model, cache_cap=cap,
+                                       block_tokens=T)
         self._step += 1
         if not self.paged:
             self._cache = cache
@@ -1603,8 +1609,7 @@ class Instance:
 
         # allocate block tables for the prompt, then scatter dense KV -> pages
         self.kv.ensure({f"seq{b}": int(lens[b]) for b in range(B)})
-        T = self.kv.block_tokens
-        nblk = -(-S // T)
+        nblk = cap // T
         self._tables_np = np.zeros((B, self.max_blocks), np.int32)
         self._nblk = np.zeros((B,), np.int64)
         per_seq = [self._pages(self.kv.block_tables[f"seq{b}"])
@@ -1620,12 +1625,7 @@ class Instance:
         self._tables = jnp.asarray(self._tables_np)
         self._tables_stale = False
 
-        # cache is [segment0][unit0] = {"k": (L, B, cap, K, hd), ...}
-        k_all = cache[0][0]["k"]
-        v_all = cache[0][0]["v"]
-        L = k_all.shape[0]
-        kc = k_all[:, :, : nblk * T].reshape(L, B, nblk, T, *k_all.shape[3:])
-        vc = v_all[:, :, : nblk * T].reshape(L, B, nblk, T, *v_all.shape[3:])
+        kc, vc = cache  # already cut into blocks: (L, B, nblk, T, K, hd)
         # ONE donated jitted scatter for the whole batch (not B slab copies)
         self.slab.k_pages, self.slab.v_pages = _scatter_prefill_kv(
             self.slab.k_pages, self.slab.v_pages, kc, vc,
@@ -1736,6 +1736,34 @@ class Instance:
             if not live:
                 del self.engine._live_instances[self.reg.model_id]
         self.engine.finish_instance(self.reg.model_id)
+
+
+# ------------------------------------------------------------ prefill forward
+@partial(jax.jit, static_argnames=("model", "cache_cap", "block_tokens"))
+def _prefill_forward(params, batch, lens, model, *, cache_cap: int,
+                     block_tokens: int):
+    """The served prefill's forward as one program.
+
+    `model` (a frozen `Model`, equal across instances of one config),
+    `cache_cap` and `block_tokens` are static, and the batch's shapes key
+    the rest, so every instance of a model reuses one compiled program per
+    prompt shape; `lens` (int32, (B,)) is traced, so lengths mixed at one
+    padded (B, S) reuse it too.  Returns the logits at each sequence's last
+    real position, (B, V), and the cache: for a paged family its K and V
+    cut into blocks, (L, B, nblk, T, K, hd) each; else the model's cache as
+    it is.
+    """
+    logits, cache = model.prefill(params, batch, cache_cap=cache_cap,
+                                  remat=False)
+    last = logits[jnp.arange(lens.shape[0]), lens - 1]
+    if not _is_paged_family(model.cfg):
+        return last, cache
+    # cache is [segment0][unit0] = {"k": (L, B, cap, K, hd), ...}
+    kv = cache[0][0]
+    L, B = kv["k"].shape[:2]
+    blocks = (L, B, cache_cap // block_tokens, block_tokens)
+    return last, tuple(kv[n].reshape(*blocks, *kv[n].shape[3:])
+                       for n in ("k", "v"))
 
 
 # ------------------------------------------------------------ prefill scatter

@@ -115,3 +115,28 @@ def test_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
     used = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
             + ma.output_size_in_bytes - ma.alias_size_in_bytes)
     assert used < HBM_BYTES, f"decode step needs {used / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("S", [128, 512])
+def test_prefill_forward_fits_one_chip(one_chip, S):
+    """The served prefill's one program (`_prefill_forward`) at deepseek-7b's
+    published widths, 8 of its 30 layers, at the benchmark's prompt lengths:
+    the forward, the last-position gather and the cache cut into blocks."""
+    cfg = dataclasses.replace(get_config("deepseek-7b"), num_layers=8)
+    model = build_model(cfg)
+    params = jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+    T = 16
+    compiled = engine_mod._prefill_forward.lower(
+        params, {"tokens": _spec(one_chip, (1, S), jnp.int32)},
+        _spec(one_chip, (1,), jnp.int32), model, cache_cap=S,
+        block_tokens=T).compile()
+    out = compiled.out_info
+    assert out[0].shape == (1, cfg.vocab_size)
+    assert [c.shape for c in out[1]] == [
+        (8, 1, S // T, T, cfg.num_kv_heads, cfg.resolved_head_dim)] * 2
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"prefill needs {used / 1e9:.2f} GB"

@@ -498,6 +498,45 @@ def test_mixed_length_batch_matches_per_sequence_reference():
     inst.finish()
 
 
+def test_prefill_lengths_share_one_program_and_match_reference_bitwise():
+    """Two instances prefill other lengths at one padded (B, S): the forward
+    is traced once (lengths are traced values, the model a static one equal
+    across instances), and each sequence's logits are bitwise those of the
+    per-sequence jitted `model.prefill`."""
+    from repro.obs import Tracer
+    from repro.obs import jit as obs_jit
+
+    # a vocabulary no other test compiles, so the first prefill traces
+    cfg = dataclasses.replace(small_cfg(), vocab_size=448)
+    model = build_model(cfg)
+    B, S = 2, 40
+    batch = mk_batch(model, B, S, seed=3)
+    eng = mk_engine()
+    eng.register("m", cfg)
+    eng.load("m")
+    params = eng.params_of("m")
+    tracer = Tracer()
+    uninstall = obs_jit.install(tracer)
+    try:
+        outs = []
+        for lens in ([40, 23], [9, 33]):
+            inst = eng.start_instance("m", num_pages=64)
+            outs.append((lens, inst.prefill(batch, lengths=lens)))
+            inst.finish()
+    finally:
+        uninstall()
+    traces = [e for e in tracer.events() if e.name == "jit.trace"
+              and "_prefill_forward" in e.args["fun"]]
+    assert len(traces) == 1
+    for lens, logits in outs:
+        for b, n in enumerate(lens):
+            sub = {k: v[b : b + 1, :n] for k, v in batch.items()}
+            rl, _ = jax.jit(lambda p, bt: model.prefill(p, bt, cache_cap=64))(
+                params, sub)
+            assert bool(jnp.array_equal(logits[b], rl[0, -1])), (lens, b)
+    eng.close()
+
+
 def test_same_model_instances_release_is_refcounted():
     """Finishing ONE of several same-model instances must not deactivate the
     model in the store — the survivor's weights would become evictable

@@ -128,6 +128,45 @@ def test_fleet_serve_span_family_per_request():
                                                                 "req:1"}
 
 
+def test_cold_starts_trace_the_prefill_program_once_per_prompt_shape():
+    """Keep-alive zero: each request starts a new instance (and model).  The
+    prefill program is traced in the first request's `prefill` span only,
+    and a new prompt length traces it exactly once more."""
+    # a vocabulary no other test compiles, so the first request traces
+    cfg = dataclasses.replace(_small_cfg(), vocab_size=320)
+    tracer = Tracer()
+    eng = Engine(256 * 1024 * 1024, tracer=tracer)
+    eng.register("m", cfg)
+    gw = FleetGateway([eng], keep_alive="zero", prompt_len=8, gen_tokens=2,
+                      tracer=tracer)
+    uninstall = obs_jit.install(tracer)
+    try:
+        for t, prompt_len in ((0, 8), (10, 8), (20, 8), (30, 12)):
+            gw.prompt_len = prompt_len
+            gw.run_trace([Request(time=float(t), model_id="m", dataset="t",
+                                  prompt_tokens=prompt_len, output_tokens=2,
+                                  batch_size=1)])
+    finally:
+        uninstall()
+        eng.close()
+    events = tracer.events()
+    serves = sorted((e for e in events if e.name == "serve"),
+                    key=lambda e: e.begin)
+    assert [s.args["cold"] for s in serves] == [True] * 4
+    per_request = []
+    for s in serves:
+        pre = next(e for e in events if e.name == "prefill"
+                   and e.thread == s.thread and s.begin <= e.begin
+                   and e.end <= s.end)
+        per_request.append([e.args["fun"] for e in events
+                            if e.name == "jit.trace"
+                            and pre.begin <= e.begin and e.end <= pre.end])
+    assert [sum("_prefill_forward" in f for f in funs)
+            for funs in per_request] == [1, 0, 0, 1]
+    # a warm shape traces nothing at all in prefill, not just the forward
+    assert per_request[1] == per_request[2] == []
+
+
 def test_tracing_off_enters_no_annotation_and_registers_no_listener(
         monkeypatch):
     import jax.monitoring as mon
