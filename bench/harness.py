@@ -25,7 +25,7 @@ from typing import Optional
 
 import numpy as np
 
-from bench import arrivals, reference, spec, weights
+from bench import arrivals, reference, spec
 from bench.peaks import peak
 from bench.readers import percentile
 
@@ -99,6 +99,10 @@ class Run:
         return self.cell.traffic
 
     @property
+    def arch(self):
+        return self.cell.arch
+
+    @property
     def peak(self):
         return peak(self.device_kind)
 
@@ -140,23 +144,22 @@ class Server:
 
         import jax
 
-        c, t = cell.config, cell.traffic
+        c, t, arch = cell.config, cell.traffic, cell.arch
         self.model_id = c["repro_model"]
         argv = ["--models", self.model_id,
                 "--smoke" if c.get("repro_smoke") else "--no-smoke",
-                "--num-layers", str(c["num_hidden_layers"]),
                 "--pool-mb", str(c["pool_mb"]),
                 "--keep-alive-policy", t["keep_alive"],
                 "--prompt-len", str(t["prompt_len"]),
-                "--gen-tokens", str(t["gen_tokens"])]
+                "--gen-tokens", str(t["gen_tokens"]), *arch.launch_args(c)]
         args = parse_args(argv)
         cfg = model_configs(args)[self.model_id]
-        _same_shapes(cfg, c)
+        arch.check_program(cfg, c)
         self.engine = Engine(args.pool_mb * 1024 * 1024, engine_id="engine0",
                              device=device)
         expected = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
         self.engine.register(self.model_id, cfg,
-                             init_fn=weights.program_init_fn(c, seed, expected))
+                             init_fn=arch.program_init_fn(c, seed, expected))
         self.gw = fleet_gateway(args, [self.engine])
         # prompts are drawn per request from a counter: start it from the
         # seed, so each seed serves other prompts
@@ -216,20 +219,6 @@ class Server:
         self._fleet.make_prefill_batch = self._make_batch
         self._fleet.generate = self._generate
         self.engine.close()
-
-
-def _same_shapes(cfg, c: dict):
-    """The program's configuration has the file's published sizes."""
-    pairs = {"num_hidden_layers": cfg.num_layers, "hidden_size": cfg.d_model,
-             "num_attention_heads": cfg.num_heads,
-             "num_key_value_heads": cfg.num_kv_heads,
-             "head_dim": cfg.resolved_head_dim,
-             "intermediate_size": cfg.d_ff, "vocab_size": cfg.vocab_size,
-             "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
-             "torch_dtype": cfg.dtype}
-    wrong = {k: (c[k], v) for k, v in pairs.items() if c[k] != v}
-    if wrong or cfg.tie_embeddings or cfg.family != "dense":
-        raise ValueError(f"program config differs from the file: {wrong}")
 
 
 # ------------------------------------------------------------ the window
@@ -368,21 +357,22 @@ def sample(served: list[Served], seed: int) -> list[Served]:
     return out
 
 
-def check(c: dict, seed: int, picked: list[Served], *, control: bool = False
-          ) -> dict:
+def check(arch, c: dict, seed: int, picked: list[Served], *,
+          control: bool = False) -> dict:
     """Widest gap by which a served token's reference logit lies below the
-    reference's best, over the picked requests.  With `control`, also the
-    same gap for the tokens the fp8 control puts first."""
-    w = weights.make(c, seed)
+    reference's best, over the picked requests: `arch`'s reference of
+    configuration `c`.  With `control`, also the same gap for the tokens
+    the fp8 control puts first."""
+    w = arch.make(c, seed)
     worst, worst_ctl = 0.0, 0.0
     for s in picked:
         toks = np.asarray(s.rec.tokens, np.int32)
         seq = np.concatenate([s.prompt, toks[:-1]])[None]
         first = len(s.prompt) - 1
-        ref = reference.logits(w, c, seq, first)
+        ref = arch.logits(w, c, seq, first)
         worst = max(worst, float(reference.gaps(ref, toks[None]).max()))
         if control:
-            ctl = reference.logits(w, c, seq, first, fp8=True)
+            ctl = arch.logits(w, c, seq, first, fp8=True)
             pick = np.asarray(ctl).argmax(-1)
             worst_ctl = max(worst_ctl, float(reference.gaps(ref, pick).max()))
     out = {"max_logit_gap": worst, "tokens": sum(len(s.rec.tokens)
@@ -511,7 +501,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         v = (setup_s if m.name == "setup_s" else m.read(run))
         if v is not None:
             metrics[m.name] = {"value": v, "unit": m.unit}
-    got = check(cell.config, seed, picked, control=control)
+    got = check(cell.arch, cell.config, seed, picked, control=control)
     limit = cell.config["check"]["max_logit_gap"]
     correct = (bool(picked) and run.failed == 0
                and got["max_logit_gap"] <= limit)

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, reference, spec, weights
+from bench import harness, spec
 from bench.tests.tiny import TINY_CONFIG, tiny_checkout
 
 
@@ -79,10 +79,10 @@ def test_broken_path_is_not_correct(cell, monkeypatch, fault):
     assert gap["value"] > gap["limit"]
 
 
-def _greedy(w, c, prompt, n):
+def _greedy(arch, w, c, prompt, n):
     seq = list(prompt)
     for _ in range(n):
-        lg = reference.logits(w, c, np.asarray(seq)[None], len(seq) - 1)
+        lg = arch.logits(w, c, np.asarray(seq)[None], len(seq) - 1)
         seq.append(int(np.asarray(lg)[0, -1].argmax()))
     return seq
 
@@ -92,14 +92,15 @@ def test_fp8_control_fails_the_limit(seed):
     """The reference's own greedy tokens read a gap of 0; what the fp8
     control puts first at the same positions reads over the limit."""
     c = TINY_CONFIG
-    w = weights.make(c, seed)
+    arch = spec.arch(c)
+    w = arch.make(c, seed)
     rng = np.random.default_rng(seed)
     picked = []
     for _ in range(4):
         prompt = rng.integers(0, c["vocab_size"], 16).astype(np.int32)
-        seq = _greedy(w, c, prompt, 48)
+        seq = _greedy(arch, w, c, prompt, 48)
         rec = type("Rec", (), {"tokens": tuple(seq[16:])})
         picked.append(harness.Served(0.0, 0.0, 0.0, 0.0, rec, prompt))
-    got = harness.check(c, seed, picked, control=True)
+    got = harness.check(arch, c, seed, picked, control=True)
     assert got["max_logit_gap"] == 0.0
     assert got["control_max_logit_gap"] > c["check"]["max_logit_gap"]

@@ -8,15 +8,9 @@ import sys
 from pathlib import Path
 
 from bench import spec
-from bench.tests.tiny import tiny_checkout
+from bench.tests.tiny import bench_files, tiny_checkout
 
 ROOT = Path(__file__).resolve().parents[2]
-
-
-def _files(root: Path) -> dict:
-    return {p.relative_to(root): p.read_bytes()
-            for p in (root / "bench").rglob("*")
-            if p.is_file() and "__pycache__" not in p.parts}
 
 
 def test_added_files_are_found_by_name(tmp_path):
@@ -31,7 +25,7 @@ def test_added_files_are_found_by_name(tmp_path):
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
 
     # every file the benchmark had is there, byte for byte
-    before, after = _files(ROOT), _files(tmp_path)
+    before, after = bench_files(ROOT), bench_files(tmp_path)
     assert all(after[p] == data for p, data in before.items())
 
     cell = spec.load_cell("tiny.cold", tmp_path)

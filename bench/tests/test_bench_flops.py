@@ -2,10 +2,11 @@
 shapes, and the table of peaks."""
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
-from bench import flops
+from bench import flops, spec
 from bench.peaks import peak
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
@@ -28,7 +29,18 @@ def _config(name):
     ("yi-9b-l8", 640, 2 * 1_646_264_320 + 8 * 4 * 4096 * 640),
 ])
 def test_decode_step_flops(name, n, hand):
-    assert flops.decode_step_flops(_config(name), n) == hand
+    c = _config(name)
+    assert flops.decode_step_flops(c, n, spec.arch(c)) == hand
+
+
+def test_decode_step_flops_counts_the_architectures_ffn():
+    """The feed-forward's work is what the architecture module counts: a
+    module that counted none leaves the attention and the head."""
+    c = _config("yi-9b-l8")
+    no_ffn = SimpleNamespace(ffn_flops=lambda c: [0] * 8)
+    mlp = 8 * 2 * 3 * 4096 * 11008
+    assert flops.decode_step_flops(c, 513, no_ffn) == (
+        flops.decode_step_flops(c, 513, spec.arch(c)) - mlp)
 
 
 @pytest.mark.parametrize("name, n, hand_flops, hand_bytes", [

@@ -16,6 +16,7 @@ ROOT = Path(__file__).resolve().parents[2]
 TINY_CONFIG = {
     "source": "https://huggingface.co/deepseek-ai/deepseek-llm-7b-base",
     "repro_model": "deepseek-7b", "repro_smoke": True,
+    "architectures": ["LlamaForCausalLM"],
     "hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4,
     "num_key_value_heads": 4, "head_dim": 32, "num_hidden_layers": 2,
     "vocab_size": 512, "rms_norm_eps": 1e-06, "rope_theta": 10000.0,
@@ -25,6 +26,13 @@ TINY_CONFIG = {
 
 TINY_TRAFFIC = {"arrival": "poisson", "rate_per_s": 4.0,
                 "prompt_len": 16, "gen_tokens": 4, "keep_alive": "zero"}
+
+
+def bench_files(root: Path) -> dict:
+    """Every file under `root`/bench, by its path, with its bytes."""
+    return {p.relative_to(root): p.read_bytes()
+            for p in (root / "bench").rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
 
 
 def tiny_checkout(dst: Path, *, with_src: bool = True) -> Path:
